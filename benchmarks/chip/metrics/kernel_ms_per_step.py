@@ -1,0 +1,12 @@
+"""Particle kernels: device milliseconds per step of the two Pallas
+kernels (deposition, gather/push), found by kernel name in the trace,
+averaged over the chips."""
+
+import trace_reduce
+
+
+def read(run):
+    if run.trace is None or run.steps == 0:
+        return None
+    s = run.trace.op_s_mean(trace_reduce.matches(trace_reduce.KERNEL))
+    return 1e3 * s / run.steps if s > 0 else None
