@@ -116,6 +116,7 @@ the host-idle-fraction and steps/s win).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -419,6 +420,9 @@ class ShardedRuntime(_StragglerMixin):
         self._mig_caps: List[Dict[int, int]] = []
         self._mig_idle: Dict[Tuple[int, int], int] = {}
         self._interval_cache: Dict[Tuple, Callable] = {}
+        #: cache key of the last dispatched interval program (names the
+        #: cause of the next cache miss in the ``interval_new_plan`` span)
+        self._dispatched_key: Optional[Tuple] = None
         tiles, species = self._pack_initial(problem.species, mig_cap)
         self._commit_state(tiles, species)
 
@@ -487,12 +491,12 @@ class ShardedRuntime(_StragglerMixin):
         # the adoption permutation, built eagerly while the state is
         # concrete (applying it later must not barrier the pipeline)
         shardings = state_shardings((tiles_dev, species_dev), self.mesh)
-        self._reorder_fn = jax.jit(
-            lambda tiles, species, p: jax.tree_util.tree_map(
-                lambda a: a[p], (tiles, species)
-            ),
-            out_shardings=shardings,
-        )
+
+        def reorder(tiles, species, p):
+            with jax.named_scope("reorder"):
+                return jax.tree_util.tree_map(lambda a: a[p], (tiles, species))
+
+        self._reorder_fn = jax.jit(reorder, out_shardings=shardings)
         self._commit_slot_tables()
         self.host_dispatches += 1
 
@@ -558,6 +562,23 @@ class ShardedRuntime(_StragglerMixin):
             tuple(self._pair_caps[o] for o in self._offsets),
             tuple(tuple(sorted(d.items())) for d in self._mig_caps),
         )
+
+    def _new_plan_cause(self, key: Tuple) -> str:
+        """Why the interval program for ``key`` (``(n_steps, plan key)``)
+        is not cached, against the last dispatched key: ``"first"`` (none
+        since the cache was last emptied), ``"exchange_plan"`` (offsets or
+        pair capacities changed), ``"pack_resize"`` (only the emigrant-pack
+        capacities changed) or ``"piece_length"`` (only ``n_steps``)."""
+        last = self._dispatched_key
+        if last is None:
+            return "first"
+        plan, last_plan = key[1], last[1]
+        # every plan key ends with the emigrant-pack capacities
+        if plan[:-1] != last_plan[:-1]:
+            return "exchange_plan"
+        if plan[-1] != last_plan[-1]:
+            return "pack_resize"
+        return "piece_length"
 
     def hop_radius(self) -> int:
         """Largest ring distance between a box's device and its curve-home
@@ -781,6 +802,7 @@ class ShardedRuntime(_StragglerMixin):
             packed.append(buf)
         if grew:
             self._interval_cache.clear()
+            self._dispatched_key = None
         return packed
 
     def _slot_cap(self, lanes: int) -> int:
@@ -889,63 +911,68 @@ class ShardedRuntime(_StragglerMixin):
             """Reference path: every pack rides the full ring (capacity-
             bounded all-to-all); every slot sees every leaver."""
             cap, mcap = caps[s], mig_caps[s][0]
-            new_box = grid.box_of_position(p.z, p.x)  # (bpd, cap) int32
-            stay = p.alive & (new_box == my_box[:, None])
-            emig = p.alive & ~stay
-            demand = emig.sum(axis=1)  # per-slot, pre-capacity
-            # compact leavers into the (mig_cap,) pack, destination-tagged
-            eidx = jnp.argsort(jnp.where(emig, 0, 1), axis=1)[:, :mcap]
-            ev = jnp.take_along_axis(emig, eidx, axis=1)
-            edest = jnp.where(ev, jnp.take_along_axis(new_box, eidx, axis=1), -1)
-            epack = {
-                k: jnp.take_along_axis(getattr(p, k), eidx, axis=1) for k in _PKEYS
-            }
-            dropped_e = emig.sum(axis=1) - ev.sum(axis=1)
-            gdest = ring_all_gather(edest, BOX_AXIS).reshape(-1)  # (S*mcap,)
-            gstack = ring_all_gather(
-                jnp.stack([epack[k] for k in _PKEYS], axis=-1), BOX_AXIS
-            ).reshape(-1, len(_PKEYS))
-            gpack = {k: gstack[:, ki] for ki, k in enumerate(_PKEYS)}
-            fields_rows = {k: getattr(p, k) for k in _PKEYS}
-            out, dropped_c = jax.vmap(make_merge(gdest, gpack, cap))(
-                stay, fields_rows, my_box, my_center
-            )
-            return out, out["alive"].sum(axis=1), dropped_e + dropped_c, demand
+            with jax.named_scope("emigrate"):
+                new_box = grid.box_of_position(p.z, p.x)  # (bpd, cap) int32
+                stay = p.alive & (new_box == my_box[:, None])
+                emig = p.alive & ~stay
+                demand = emig.sum(axis=1)  # per-slot, pre-capacity
+                # compact leavers into the (mig_cap,) pack, destination-tagged
+                eidx = jnp.argsort(jnp.where(emig, 0, 1), axis=1)[:, :mcap]
+                ev = jnp.take_along_axis(emig, eidx, axis=1)
+                edest = jnp.where(ev, jnp.take_along_axis(new_box, eidx, axis=1), -1)
+                epack = {
+                    k: jnp.take_along_axis(getattr(p, k), eidx, axis=1) for k in _PKEYS
+                }
+                dropped_e = emig.sum(axis=1) - ev.sum(axis=1)
+            with jax.named_scope("exchange"):
+                gdest = ring_all_gather(edest, BOX_AXIS).reshape(-1)  # (S*mcap,)
+                gstack = ring_all_gather(
+                    jnp.stack([epack[k] for k in _PKEYS], axis=-1), BOX_AXIS
+                ).reshape(-1, len(_PKEYS))
+                gpack = {k: gstack[:, ki] for ki, k in enumerate(_PKEYS)}
+            with jax.named_scope("merge"):
+                fields_rows = {k: getattr(p, k) for k in _PKEYS}
+                out, dropped_c = jax.vmap(make_merge(gdest, gpack, cap))(
+                    stay, fields_rows, my_box, my_center
+                )
+            with jax.named_scope("diagnostics"):
+                return out, out["alive"].sum(axis=1), dropped_e + dropped_c, demand
 
         def local_interval(tiles, species, slot_box, slot_of, t0):
             # local shapes: tiles (bpd, 6, bnz, bnx); species leaves
             # (bpd, cap); slot_box (bpd,) — the device's slice of the
             # mapping; slot_of (S,) — its host-provided inverse, replicated
-            my_dev = jax.lax.axis_index(BOX_AXIS)
-            my_origin = ORIGINS[slot_box]
-            my_static = STATICS[slot_box]
-            my_center = CENTERS[slot_box]
-            my_box = slot_box
+            with jax.named_scope("pair_tables"):
+                my_dev = jax.lax.axis_index(BOX_AXIS)
+                my_origin = ORIGINS[slot_box]
+                my_static = STATICS[slot_box]
+                my_center = CENTERS[slot_box]
+                my_box = slot_box
 
-            if comm == "ring":
-                sb_all = ring_all_gather(slot_box, BOX_AXIS)  # (S,)
-                my_cmap = CELL_MAP[slot_box]  # (bpd, pn, pn)
-                cmap_all = CELL_MAP[sb_all]  # (S, pn, pn)
-                imap_all = INT_MAP[sb_all]  # (S, bnz, bnx)
-            else:
-                # directional pair tables, once per interval: slot i sends
-                # its direction-j strip to the owner of SEND_TO[box, j];
-                # bucket the (slot, dir) pairs by ring offset, compacted to
-                # the host-computed per-offset capacity
-                send_to = SEND_TO[slot_box]  # (bpd, 8)
-                off_pair = (slot_of[send_to] // bpd - my_dev) % n_dev
-                flat_off = off_pair.reshape(-1)
-                flat_dst = send_to.reshape(-1)
-                pairs = {}
-                for o in offsets:
-                    fl = flat_off == o
-                    sel = jnp.argsort(jnp.where(fl, 0, 1))[: pair_caps[o]]
-                    valid = fl[sel]
-                    pairs[o] = (
-                        (sel // 8).astype(jnp.int32),
-                        (sel % 8).astype(jnp.int32),
-                        jnp.where(valid, flat_dst[sel], -1).astype(jnp.int32),
-                    )
+                if comm == "ring":
+                    sb_all = ring_all_gather(slot_box, BOX_AXIS)  # (S,)
+                    my_cmap = CELL_MAP[slot_box]  # (bpd, pn, pn)
+                    cmap_all = CELL_MAP[sb_all]  # (S, pn, pn)
+                    imap_all = INT_MAP[sb_all]  # (S, bnz, bnx)
+                else:
+                    # directional pair tables, once per interval: slot i sends
+                    # its direction-j strip to the owner of SEND_TO[box, j];
+                    # bucket the (slot, dir) pairs by ring offset, compacted to
+                    # the host-computed per-offset capacity
+                    send_to = SEND_TO[slot_box]  # (bpd, 8)
+                    off_pair = (slot_of[send_to] // bpd - my_dev) % n_dev
+                    flat_off = off_pair.reshape(-1)
+                    flat_dst = send_to.reshape(-1)
+                    pairs = {}
+                    for o in offsets:
+                        fl = flat_off == o
+                        sel = jnp.argsort(jnp.where(fl, 0, 1))[: pair_caps[o]]
+                        valid = fl[sel]
+                        pairs[o] = (
+                            (sel // 8).astype(jnp.int32),
+                            (sel % 8).astype(jnp.int32),
+                            jnp.where(valid, flat_dst[sel], -1).astype(jnp.int32),
+                        )
 
             def strip_payloads(src_flat, table):
                 """Per-offset (values, dst_box, dir) payloads gathered from
@@ -1024,148 +1051,171 @@ class ShardedRuntime(_StragglerMixin):
                 the ring offset of their destination's owner, one hop per
                 offset, arrivals merged into the addressed slots."""
                 cap = caps[s]
-                new_box = grid.box_of_position(p.z, p.x)  # (bpd, cap)
-                stay = p.alive & (new_box == my_box[:, None])
-                emig = (p.alive & ~stay).reshape(-1)
-                nb_flat = new_box.reshape(-1)
-                e_off = (slot_of[nb_flat] // bpd - my_dev) % n_dev
-                fields_flat = {k: getattr(p, k).reshape(-1) for k in _PKEYS}
-                payloads, demand, packed = {}, [], 0
-                for o in offsets:
-                    fl = emig & (e_off == o)
-                    sel = jnp.argsort(jnp.where(fl, 0, 1))[: mig_caps[s][o]]
-                    valid = fl[sel]
-                    pk = jnp.stack([fields_flat[k][sel] for k in _PKEYS], axis=-1)
-                    payloads[o] = (pk, jnp.where(valid, nb_flat[sel], -1))
-                    demand.append(fl.sum())
-                    packed = packed + valid.sum()
-                dropped_e = emig.sum() - packed  # off-plan or overflow
-                arrivals = neighbor_exchange(payloads, BOX_AXIS)
-                gstack = jnp.concatenate([arrivals[o][0] for o in offsets])
-                gdest = jnp.concatenate([arrivals[o][1] for o in offsets])
-                gpack = {k: gstack[:, ki] for ki, k in enumerate(_PKEYS)}
-                fields_rows = {k: getattr(p, k) for k in _PKEYS}
-                out, dropped_c = jax.vmap(make_merge(gdest, gpack, cap))(
-                    stay, fields_rows, my_box, my_center
-                )
-                dropped = dropped_c.at[0].add(dropped_e)
-                return (
-                    out,
-                    out["alive"].sum(axis=1),
-                    dropped,
-                    jnp.stack(demand).astype(jnp.int32),
-                )
+                with jax.named_scope("emigrate"):
+                    new_box = grid.box_of_position(p.z, p.x)  # (bpd, cap)
+                    stay = p.alive & (new_box == my_box[:, None])
+                    emig = (p.alive & ~stay).reshape(-1)
+                    nb_flat = new_box.reshape(-1)
+                    e_off = (slot_of[nb_flat] // bpd - my_dev) % n_dev
+                    fields_flat = {k: getattr(p, k).reshape(-1) for k in _PKEYS}
+                    payloads, demand, packed = {}, [], 0
+                    for o in offsets:
+                        fl = emig & (e_off == o)
+                        sel = jnp.argsort(jnp.where(fl, 0, 1))[: mig_caps[s][o]]
+                        valid = fl[sel]
+                        pk = jnp.stack([fields_flat[k][sel] for k in _PKEYS], axis=-1)
+                        payloads[o] = (pk, jnp.where(valid, nb_flat[sel], -1))
+                        demand.append(fl.sum())
+                        packed = packed + valid.sum()
+                    dropped_e = emig.sum() - packed  # off-plan or overflow
+                with jax.named_scope("exchange"):
+                    arrivals = neighbor_exchange(payloads, BOX_AXIS)
+                    gstack = jnp.concatenate([arrivals[o][0] for o in offsets])
+                    gdest = jnp.concatenate([arrivals[o][1] for o in offsets])
+                    gpack = {k: gstack[:, ki] for ki, k in enumerate(_PKEYS)}
+                with jax.named_scope("merge"):
+                    fields_rows = {k: getattr(p, k) for k in _PKEYS}
+                    out, dropped_c = jax.vmap(make_merge(gdest, gpack, cap))(
+                        stay, fields_rows, my_box, my_center
+                    )
+                with jax.named_scope("diagnostics"):
+                    dropped = dropped_c.at[0].add(dropped_e)
+                    alive = out["alive"].sum(axis=1)
+                # with one offset this stack is a lone broadcast that folds
+                # into the step's stack of the species' demands; XLA names
+                # a folded instruction after the part behind the last
+                # scope of its locations, so a scope here would rename it
+                with (
+                    jax.named_scope("emigrate")
+                    if len(offsets) > 1
+                    else contextlib.nullcontext()
+                ):
+                    return out, alive, dropped, jnp.stack(demand).astype(jnp.int32)
 
             def step(carry, i):
                 tiles, species = carry
-                t = t0 + i * dt
+                with jax.named_scope("field_phase"):
+                    t = t0 + i * dt
                 # 1. halo paste: guard strips (neighbor) or interiors
                 #    around the full ring (ring reference)
-                if comm == "ring":
-                    ints_all = ring_all_gather(tiles, BOX_AXIS)  # (S, 6, bnz, bnx)
-                    gF = (
-                        jnp.zeros((6, grid.n_cells), jnp.float32)
-                        .at[:, imap_all.reshape(-1)]
-                        .set(
-                            ints_all.transpose(1, 0, 2, 3).reshape(6, -1),
-                            unique_indices=True,
+                with jax.named_scope("halo_paste"):
+                    if comm == "ring":
+                        ints_all = ring_all_gather(tiles, BOX_AXIS)  # (S, 6, bnz, bnx)
+                        gF = (
+                            jnp.zeros((6, grid.n_cells), jnp.float32)
+                            .at[:, imap_all.reshape(-1)]
+                            .set(
+                                ints_all.transpose(1, 0, 2, 3).reshape(6, -1),
+                                unique_indices=True,
+                            )
                         )
-                    )
-                    padded = jnp.moveaxis(gF[:, my_cmap], 1, 0)  # (bpd, 6, pn, pn)
-                else:
-                    padded = halo_paste_neighbor(tiles)
+                        padded = jnp.moveaxis(gF[:, my_cmap], 1, 0)  # (bpd, 6, pn, pn)
+                    else:
+                        padded = halo_paste_neighbor(tiles)
                 # 2. particle phase on all owned slots at once
                 sp_in = tuple(to_particles(d, s) for s, d in enumerate(species))
                 if overlap:
                     # split-phase: advance everything, deposit the frontier
                     # only — the strips the fold sends are complete now
-                    sp2, jF, counts, flags = particle_phase_stacked_frontier(
-                        padded, sp_in, my_origin, local_grid,
-                        domain_grid=grid, shape_order=order,
-                        frontier_mask=FRONTIER,
-                    )
-                    work = box_work_counters(counts, grid)
+                    with jax.named_scope("particle_phase"):
+                        sp2, jF, counts, flags = particle_phase_stacked_frontier(
+                            padded, sp_in, my_origin, local_grid,
+                            domain_grid=grid, shape_order=order,
+                            frontier_mask=FRONTIER,
+                        )
+                        work = box_work_counters(counts, grid)
                     # 3. issue the fold collectives from the frontier
                     #    deposit, run the interior deposit inside their
                     #    dataflow window, fold arrivals in afterwards
                     if comm == "ring":
-                        jF, (sp2, flags) = jax.lax.optimization_barrier(
-                            (jF, (sp2, flags))
-                        )
-                        j_all = ring_all_gather(jF, BOX_AXIS)  # (S, 3, pn, pn)
-                        jI = particle_phase_stacked_interior(
-                            sp2, my_origin, local_grid,
-                            shape_order=order, frontier_flags=flags,
-                        )
-                        gJ = (
-                            jnp.zeros((3, grid.n_cells), jnp.float32)
-                            .at[:, cmap_all.reshape(-1)]
-                            .add(j_all.transpose(1, 0, 2, 3).reshape(3, -1))
-                        )
-                        # interior deposits never reach another frame's
-                        # view (they sit >= halo inside their own box), so
-                        # the local tile add reproduces the global fold
-                        jp = jnp.moveaxis(gJ[:, my_cmap], 1, 0) + jI
+                        with jax.named_scope("current_fold"):
+                            jF, (sp2, flags) = jax.lax.optimization_barrier(
+                                (jF, (sp2, flags))
+                            )
+                            j_all = ring_all_gather(jF, BOX_AXIS)  # (S, 3, pn, pn)
+                        with jax.named_scope("particle_phase"):
+                            jI = particle_phase_stacked_interior(
+                                sp2, my_origin, local_grid,
+                                shape_order=order, frontier_flags=flags,
+                            )
+                        with jax.named_scope("current_fold"):
+                            gJ = (
+                                jnp.zeros((3, grid.n_cells), jnp.float32)
+                                .at[:, cmap_all.reshape(-1)]
+                                .add(j_all.transpose(1, 0, 2, 3).reshape(3, -1))
+                            )
+                            # interior deposits never reach another frame's
+                            # view (they sit >= halo inside their own box), so
+                            # the local tile add reproduces the global fold
+                            jp = jnp.moveaxis(gJ[:, my_cmap], 1, 0) + jI
                     else:
-                        handle, (sp2, flags) = neighbor_exchange_start(
-                            strip_payloads(jF.reshape(bpd, 3, PNSQ), FOLD_SRC),
-                            BOX_AXIS,
-                            carry=(sp2, flags),
-                        )
-                        jI = particle_phase_stacked_interior(
-                            sp2, my_origin, local_grid,
-                            shape_order=order, frontier_flags=flags,
-                        )
-                        j3 = jF + jI
-                        acc = jnp.concatenate(
-                            [
-                                j3.transpose(1, 0, 2, 3).reshape(3, -1),
-                                jnp.zeros((3, 1), jnp.float32),
-                            ],
-                            axis=1,
-                        )
-                        arrivals = neighbor_exchange_done(handle)
-                        fold = strip_scatter(FOLD_DST)
-                        for o in sorted(arrivals):
-                            acc = fold(acc, o, arrivals[o])
-                        jp = (
-                            acc[:, : bpd * PNSQ]
-                            .reshape(3, bpd, pnz, pnx)
-                            .transpose(1, 0, 2, 3)
-                        )
+                        with jax.named_scope("current_fold"):
+                            handle, (sp2, flags) = neighbor_exchange_start(
+                                strip_payloads(jF.reshape(bpd, 3, PNSQ), FOLD_SRC),
+                                BOX_AXIS,
+                                carry=(sp2, flags),
+                            )
+                        with jax.named_scope("particle_phase"):
+                            jI = particle_phase_stacked_interior(
+                                sp2, my_origin, local_grid,
+                                shape_order=order, frontier_flags=flags,
+                            )
+                        with jax.named_scope("current_fold"):
+                            j3 = jF + jI
+                            acc = jnp.concatenate(
+                                [
+                                    j3.transpose(1, 0, 2, 3).reshape(3, -1),
+                                    jnp.zeros((3, 1), jnp.float32),
+                                ],
+                                axis=1,
+                            )
+                            arrivals = neighbor_exchange_done(handle)
+                            fold = strip_scatter(FOLD_DST)
+                            for o in sorted(arrivals):
+                                acc = fold(acc, o, arrivals[o])
+                            jp = (
+                                acc[:, : bpd * PNSQ]
+                                .reshape(3, bpd, pnz, pnx)
+                                .transpose(1, 0, 2, 3)
+                            )
                 else:
-                    if engine_backend == "pallas":
-                        # slot-batched Pallas kernels: the balancer's work
-                        # signal is the in-kernel executed-tile counters,
-                        # not the host-derived box_work_counters formula
-                        sp2, j3, counts, work = particle_phase_slots(
-                            padded, sp_in, my_origin, local_grid,
-                            domain_grid=grid, interpret=interpret,
-                        )
-                    else:
-                        sp2, j3, counts = particle_phase_stacked(
-                            padded, sp_in, my_origin, local_grid,
-                            domain_grid=grid, shape_order=order,
-                        )
-                        work = box_work_counters(counts, grid)
+                    with jax.named_scope("particle_phase"):
+                        if engine_backend == "pallas":
+                            # slot-batched Pallas kernels: the balancer's work
+                            # signal is the in-kernel executed-tile counters,
+                            # not the host-derived box_work_counters formula
+                            sp2, j3, counts, work = particle_phase_slots(
+                                padded, sp_in, my_origin, local_grid,
+                                domain_grid=grid, interpret=interpret,
+                            )
+                        else:
+                            sp2, j3, counts = particle_phase_stacked(
+                                padded, sp_in, my_origin, local_grid,
+                                domain_grid=grid, shape_order=order,
+                            )
+                            work = box_work_counters(counts, grid)
                     # 3. current fold: overlapping deposit strips scatter-
                     #    add into each padded frame (strip form of
                     #    halo_fold_plan)
-                    if comm == "ring":
-                        j_all = ring_all_gather(j3, BOX_AXIS)  # (S, 3, pn, pn)
-                        gJ = (
-                            jnp.zeros((3, grid.n_cells), jnp.float32)
-                            .at[:, cmap_all.reshape(-1)]
-                            .add(j_all.transpose(1, 0, 2, 3).reshape(3, -1))
-                        )
-                        jp = jnp.moveaxis(gJ[:, my_cmap], 1, 0)  # (bpd, 3, pn, pn)
-                    else:
-                        jp = current_fold_neighbor(j3)
+                    with jax.named_scope("current_fold"):
+                        if comm == "ring":
+                            j_all = ring_all_gather(j3, BOX_AXIS)  # (S, 3, pn, pn)
+                            gJ = (
+                                jnp.zeros((3, grid.n_cells), jnp.float32)
+                                .at[:, cmap_all.reshape(-1)]
+                                .add(j_all.transpose(1, 0, 2, 3).reshape(3, -1))
+                            )
+                            jp = jnp.moveaxis(gJ[:, my_cmap], 1, 0)  # (bpd, 3, pn, pn)
+                        else:
+                            jp = current_fold_neighbor(j3)
                 # 4. field phase, keep interiors
-                tiles2 = field_phase_stacked(
-                    padded, jp, my_static, t, local_grid, halo, laser=laser
-                )
-                # 5. emigration: destination-aware packs (or the full ring)
+                with jax.named_scope("field_phase"):
+                    tiles2 = field_phase_stacked(
+                        padded, jp, my_static, t, local_grid, halo, laser=laser
+                    )
+                # 5. emigration: destination-aware packs (or the full ring);
+                #    the exchanges scope their own emigrate / exchange /
+                #    merge / diagnostics parts
                 new_species, alive, dropped = [], 0, 0
                 demand = []
                 ke = 0.0
@@ -1178,21 +1228,23 @@ class ShardedRuntime(_StragglerMixin):
                         out, alive_s, dropped_s, demand_s = exchange_neighbor(p, s)
                     new_species.append(out)
                     demand.append(demand_s)
-                    alive = alive + alive_s
-                    dropped = dropped + dropped_s
-                    ke = ke + jax.vmap(kinetic_energy, in_axes=(_P_AXES,))(
-                        to_particles(out, s)
-                    )
-                fe = dv * jnp.sum(tiles2.astype(jnp.float32) ** 2, axis=(1, 2, 3))
-                outs = {
-                    "counts": counts,
-                    "work": work,
-                    "alive": alive.astype(jnp.int32),
-                    "dropped": dropped.astype(jnp.int32),
-                    "field_energy": fe,
-                    "kinetic_energy": ke,
-                    "emig_demand": jnp.stack(demand).astype(jnp.int32),
-                }
+                    with jax.named_scope("diagnostics"):
+                        alive = alive + alive_s
+                        dropped = dropped + dropped_s
+                        ke = ke + jax.vmap(kinetic_energy, in_axes=(_P_AXES,))(
+                            to_particles(out, s)
+                        )
+                with jax.named_scope("diagnostics"):
+                    fe = dv * jnp.sum(tiles2.astype(jnp.float32) ** 2, axis=(1, 2, 3))
+                    outs = {
+                        "counts": counts,
+                        "work": work,
+                        "alive": alive.astype(jnp.int32),
+                        "dropped": dropped.astype(jnp.int32),
+                        "field_energy": fe,
+                        "kinetic_energy": ke,
+                        "emig_demand": jnp.stack(demand).astype(jnp.int32),
+                    }
                 return (tiles2, tuple(new_species)), outs
 
             (tiles, species), ys = jax.lax.scan(
@@ -1292,30 +1344,38 @@ class ShardedRuntime(_StragglerMixin):
         ``"async"`` (depth 2 — the previous round's history is fetched
         while this piece executes, and any adoption it triggers corrects
         the in-flight state one interval late)."""
-        fn = self._interval_fn(n_steps)
-        meta = {
-            "n_steps": n_steps,
-            "step_idx": self.step_idx,
-            "lb_due": self.balancer.should_run(self.step_idx),
-            # histories are slot-ordered under the *dispatch-time* mapping;
-            # the harvester must not read them through a later slot_box
-            # (nor credit their work through a later box->device mapping)
-            "slot_box": self._slot_box.copy(),
-            "mapping": self.balancer.mapping.copy(),
-            "mig_keys": self._mig_keys(),
-        }
+        key = (n_steps, self._plan_key())
+        cause = None if key in self._interval_cache else self._new_plan_cause(key)
+        with jax.profiler.TraceAnnotation("interval_dispatch"), (
+            jax.profiler.TraceAnnotation("interval_new_plan", cause=cause)
+            if cause
+            else contextlib.nullcontext()
+        ):
+            fn = self._interval_fn(n_steps)
+            meta = {
+                "n_steps": n_steps,
+                "step_idx": self.step_idx,
+                "lb_due": self.balancer.should_run(self.step_idx),
+                # histories are slot-ordered under the *dispatch-time* mapping;
+                # the harvester must not read them through a later slot_box
+                # (nor credit their work through a later box->device mapping)
+                "slot_box": self._slot_box.copy(),
+                "mapping": self.balancer.mapping.copy(),
+                "mig_keys": self._mig_keys(),
+            }
 
-        def program(state, slot_box_dev, slot_of_dev, t):
-            tiles, species, ys = fn(state[0], state[1], slot_box_dev, slot_of_dev, t)
-            return (tiles, species), ys
+            def program(state, slot_box_dev, slot_of_dev, t):
+                tiles, species, ys = fn(state[0], state[1], slot_box_dev, slot_of_dev, t)
+                return (tiles, species), ys
 
-        self._pipe.enqueue(
-            program,
-            self._slot_box_dev,
-            self._slot_of_dev,
-            jnp.float32(self.t),
-            meta=meta,
-        )
+            self._pipe.enqueue(
+                program,
+                self._slot_box_dev,
+                self._slot_of_dev,
+                jnp.float32(self.t),
+                meta=meta,
+            )
+        self._dispatched_key = key
         self.host_dispatches += 1
         self.step_idx += n_steps
         self.t += n_steps * self.grid.dt
@@ -1335,30 +1395,33 @@ class ShardedRuntime(_StragglerMixin):
             return
         host, meta = harvested
         self.host_syncs += 1
-        n_steps = meta["n_steps"]
-        sb = meta["slot_box"]  # (S,) box per slot at dispatch time
-        n_boxes = self.grid.n_boxes
-        work_box = np.empty((n_steps, n_boxes))
-        work_box[:, sb] = np.asarray(host["work"], np.float64)
-        counts_box = np.empty((n_steps, n_boxes))
-        counts_box[:, sb] = np.asarray(host["counts"], np.float64)
-        alive_box = np.empty((n_steps, n_boxes))
-        alive_box[:, sb] = np.asarray(host["alive"], np.float64)
-        self._alive_by_box = alive_box[-1]
-        self.dropped_total += int(np.asarray(host["dropped"]).sum())
-        self._adapt_mig(
-            np.asarray(host["emig_demand"]),
-            keys=meta["mig_keys"],
-            step=meta["step_idx"],
-        )
-        self.history["field_energy"].extend(
-            float(v) for v in np.asarray(host["field_energy"]).sum(axis=1)
-        )
-        self.history["kinetic_energy"].extend(
-            float(v) for v in np.asarray(host["kinetic_energy"]).sum(axis=1)
-        )
+        with jax.profiler.TraceAnnotation("history_decode"):
+            n_steps = meta["n_steps"]
+            sb = meta["slot_box"]  # (S,) box per slot at dispatch time
+            n_boxes = self.grid.n_boxes
+            work_box = np.empty((n_steps, n_boxes))
+            work_box[:, sb] = np.asarray(host["work"], np.float64)
+            counts_box = np.empty((n_steps, n_boxes))
+            counts_box[:, sb] = np.asarray(host["counts"], np.float64)
+            alive_box = np.empty((n_steps, n_boxes))
+            alive_box[:, sb] = np.asarray(host["alive"], np.float64)
+            self._alive_by_box = alive_box[-1]
+            self.dropped_total += int(np.asarray(host["dropped"]).sum())
+            self._adapt_mig(
+                np.asarray(host["emig_demand"]),
+                keys=meta["mig_keys"],
+                step=meta["step_idx"],
+            )
+            self.history["field_energy"].extend(
+                float(v) for v in np.asarray(host["field_energy"]).sum(axis=1)
+            )
+            self.history["kinetic_energy"].extend(
+                float(v) for v in np.asarray(host["kinetic_energy"]).sum(axis=1)
+            )
 
-        if meta["lb_due"]:
+        if not meta["lb_due"]:
+            return
+        with jax.profiler.TraceAnnotation("lb_decide"):
             # row 0 is the round-boundary step — what per-step execution
             # would have fed the balancer
             self._observe_straggler(work_box[0], meta["mapping"])
@@ -1378,9 +1441,10 @@ class ShardedRuntime(_StragglerMixin):
                         self.n_devices,
                         max_shift=self.locality_shift,
                     )
-                self.balancer.mapping = new_mapping
-                self.history["lb_steps"].append(meta["step_idx"])
-                self._recommit(new_mapping)
+        if new_mapping is not None:
+            self.balancer.mapping = new_mapping
+            self.history["lb_steps"].append(meta["step_idx"])
+            self._recommit(new_mapping)
 
     # ------------------------------------------------------------------
     # adoption: re-commit the sharding as a slot permutation
@@ -1433,55 +1497,57 @@ class ShardedRuntime(_StragglerMixin):
         pipeline's tail state, so under ``pipeline="async"`` it corrects
         the in-flight round's output — landing one interval after the
         counters that motivated it, without a stall."""
-        S, bpd = self.grid.n_boxes, self._bpd
-        old_slot_of_box = np.empty(S, np.int64)
-        old_slot_of_box[self._slot_box] = np.arange(S)
-        new_slot_box = -np.ones(S, np.int64)
-        for d in range(self.n_devices):
-            slots = np.arange(d * bpd, (d + 1) * bpd)
-            # boxes staying on d keep their slots (they do not move at all)
-            stay = [s for s in slots if new_mapping[self._slot_box[s]] == d]
-            for s in stay:
-                new_slot_box[s] = self._slot_box[s]
-            incoming = [
-                b
-                for b in np.where(new_mapping == d)[0]
-                if new_slot_box[old_slot_of_box[b]] != b
-            ]
-            incoming.sort(key=lambda b: self._curve[b])
-            free = [s for s in slots if new_slot_box[s] < 0]
-            for s, b in zip(free, incoming):
-                new_slot_box[s] = b
-        assert (new_slot_box >= 0).all() and len(set(new_slot_box)) == S
-        perm = old_slot_of_box[new_slot_box]
+        moved = int(np.count_nonzero(self._slot_of_box() // self._bpd != new_mapping))
+        with jax.profiler.TraceAnnotation("recommit", boxes_moved=moved):
+            S, bpd = self.grid.n_boxes, self._bpd
+            old_slot_of_box = np.empty(S, np.int64)
+            old_slot_of_box[self._slot_box] = np.arange(S)
+            new_slot_box = -np.ones(S, np.int64)
+            for d in range(self.n_devices):
+                slots = np.arange(d * bpd, (d + 1) * bpd)
+                # boxes staying on d keep their slots (they do not move at all)
+                stay = [s for s in slots if new_mapping[self._slot_box[s]] == d]
+                for s in stay:
+                    new_slot_box[s] = self._slot_box[s]
+                incoming = [
+                    b
+                    for b in np.where(new_mapping == d)[0]
+                    if new_slot_box[old_slot_of_box[b]] != b
+                ]
+                incoming.sort(key=lambda b: self._curve[b])
+                free = [s for s in slots if new_slot_box[s] < 0]
+                for s, b in zip(free, incoming):
+                    new_slot_box[s] = b
+            assert (new_slot_box >= 0).all() and len(set(new_slot_box)) == S
+            perm = old_slot_of_box[new_slot_box]
 
-        self._pipe.correct(
-            lambda state, p: self._reorder_fn(state[0], state[1], p),
-            jnp.asarray(perm),
-        )
-        self._slot_box = new_slot_box
-        slot_dev = jnp.asarray(new_slot_box.astype(np.int32))
-        self._slot_box_dev = jax.device_put(
-            slot_dev, state_shardings(slot_dev, self.mesh)
-        )
-        self._commit_slot_tables()
-        if self.comm == "neighbor":
-            old_offsets = self._offsets
-            self._build_comm_plan()
-            if self._offsets != old_offsets:
-                # keep learned pack capacities on surviving offsets; new
-                # offsets start from the capacity floor (demand-driven
-                # growth reacts within one interval if they run hot)
-                for s, d in enumerate(self._mig_caps):
-                    self._mig_caps[s] = {
-                        o: d.get(o, _MIN_MIG) for o in self._offsets
+            self._pipe.correct(
+                lambda state, p: self._reorder_fn(state[0], state[1], p),
+                jnp.asarray(perm),
+            )
+            self._slot_box = new_slot_box
+            slot_dev = jnp.asarray(new_slot_box.astype(np.int32))
+            self._slot_box_dev = jax.device_put(
+                slot_dev, state_shardings(slot_dev, self.mesh)
+            )
+            self._commit_slot_tables()
+            if self.comm == "neighbor":
+                old_offsets = self._offsets
+                self._build_comm_plan()
+                if self._offsets != old_offsets:
+                    # keep learned pack capacities on surviving offsets; new
+                    # offsets start from the capacity floor (demand-driven
+                    # growth reacts within one interval if they run hot)
+                    for s, d in enumerate(self._mig_caps):
+                        self._mig_caps[s] = {
+                            o: d.get(o, _MIN_MIG) for o in self._offsets
+                        }
+                    self._mig_idle = {
+                        (s, o): v
+                        for (s, o), v in self._mig_idle.items()
+                        if o in self._offsets
                     }
-                self._mig_idle = {
-                    (s, o): v
-                    for (s, o), v in self._mig_idle.items()
-                    if o in self._offsets
-                }
-        self.host_dispatches += 2  # the reorder program + the mapping commit
+            self.host_dispatches += 2  # the reorder program + the mapping commit
 
     # ------------------------------------------------------------------
     # capacity awareness (straggler mitigation hook)
@@ -1518,6 +1584,28 @@ class ShardedRuntime(_StragglerMixin):
         (pipeline flushed first)."""
         self.flush()
         return self._alive_by_box.copy()
+
+    def slot_occupancy(self) -> Dict[str, np.ndarray]:
+        """The slot layout as it stands (pipeline flushed first), read from
+        each device's shard of the particle buffers: per slot, the box it
+        holds (``box``, ``(S,)``), the id of the device holding it
+        (``device``, ``(S,)``), and per species its alive particles
+        (``alive``, ``(n_species, S)``) and particle lanes (``lanes``,
+        ``(n_species, S)``).  One device sync per shard; never on the hot
+        path.  Lanes not holding an alive particle are the dead lanes every
+        step's exchange still sorts and gathers."""
+        self.flush()
+        S = self.grid.n_boxes
+        device = np.full(S, -1, np.int64)
+        alive = np.zeros((len(self._qm), S), np.int64)
+        lanes = np.zeros((len(self._qm), S), np.int64)
+        for s, d in enumerate(self._species):
+            for shard in d["alive"].addressable_shards:
+                slots = np.arange(S)[shard.index[0]]
+                device[slots] = shard.device.id
+                alive[s, slots] = np.asarray(jnp.sum(shard.data, axis=1, dtype=jnp.int32))
+                lanes[s, slots] = shard.data.shape[1]
+        return {"box": self._slot_box.copy(), "device": device, "alive": alive, "lanes": lanes}
 
     # ------------------------------------------------------------------
     # recovery surface (see repro.dist.recovery)

@@ -628,15 +628,25 @@ class IntervalPipeline:
         sync) and return ``(host_history, meta)``; ``None`` when nothing is
         in flight.  The wait + fetch accumulate in :attr:`host_blocked_s`
         — under ``depth >= 2`` the balancer work that follows overlaps the
-        next round's device compute, which is the pipeline's win."""
+        next round's device compute, which is the pipeline's win.  In a
+        profile they are the host spans ``harvest_wait`` (the round's
+        dispatch and device work) and ``harvest_fetch`` (the copy)."""
         if not self._inflight:
             return None
         self._absorb_overlap()
         history, meta = self._inflight.popleft()
         t0 = time.perf_counter()
-        if isinstance(history, Future):
-            history = history.result()
-        host = jax.device_get(history)
+        with jax.profiler.TraceAnnotation("harvest_wait"):
+            if isinstance(history, Future):
+                history = history.result()
+            # queue the copies before the wait, as a bare device_get
+            # would: they start as soon as the runtime marks the round done
+            for leaf in jax.tree_util.tree_leaves(history):
+                if isinstance(leaf, jax.Array):
+                    leaf.copy_to_host_async()
+            jax.block_until_ready(history)
+        with jax.profiler.TraceAnnotation("harvest_fetch"):
+            host = jax.device_get(history)
         self.host_blocked_s += time.perf_counter() - t0
         # every task enqueued before this round's dispatch has run by now,
         # so a failed correction preceding it is visible here
