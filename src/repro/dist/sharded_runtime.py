@@ -190,6 +190,47 @@ def _pad_tables(tables) -> np.ndarray:
     return out
 
 
+def merge_slots(stay, fields, boxes, centers, gdest, gpack, cap: int):
+    """Merge each slot's stayers with the arrivals addressed to its box.
+
+    ``stay`` (slots, lanes) marks the lanes that keep their particle,
+    ``fields`` holds the slot's ``_PKEYS`` arrays, ``boxes`` (slots,) and
+    ``centers`` (slots, 2) each slot's box and box centre; ``gdest``
+    (arrivals,) and ``gpack`` (each ``_PKEYS`` array of that width) are
+    the arrivals, tagged with their destination box.  Returns the merged
+    slots (``_PKEYS`` and ``alive``, ``cap`` lanes each) and the valid
+    particles each slot dropped past ``cap``.
+
+    One stable sort on validity carries the payload, so the valid lanes
+    come first in their old order, with no per-lane gathers.  Slots are
+    merged one at a time, which holds the sort's operands to one slot's
+    lanes.
+    """
+
+    def merge(args):
+        stay_r, fields_r, box_r, center_r = args
+        valid = jnp.concatenate([stay_r, gdest == box_r])
+        n_valid = valid.sum()
+        sorted_ = jax.lax.sort(
+            (jnp.where(valid, 0, 1),)
+            + tuple(jnp.concatenate([fields_r[k], gpack[k]]) for k in _PKEYS),
+            num_keys=1,
+            is_stable=True,
+        )
+        out = {k: v[:cap] for k, v in zip(_PKEYS, sorted_[1:])}
+        new_alive = jnp.arange(cap) < jnp.minimum(n_valid, cap)
+        # park dead entries at the box centre, zero their payload
+        out["z"] = jnp.where(new_alive, out["z"], center_r[0])
+        out["x"] = jnp.where(new_alive, out["x"], center_r[1])
+        for k in ("ux", "uy", "uz", "w"):
+            out[k] = jnp.where(new_alive, out[k], 0.0)
+        out["alive"] = new_alive
+        dropped_c = n_valid - new_alive.sum()
+        return out, dropped_c
+
+    return jax.lax.map(merge, (stay, fields, boxes, centers))
+
+
 class ShardedRuntime(_StragglerMixin):
     """Step a ``ProblemSetup`` as one sharded XLA program per LB interval.
 
@@ -885,28 +926,6 @@ class ShardedRuntime(_StragglerMixin):
                 q=jnp.float32(q), m=jnp.float32(m),
             )
 
-        def make_merge(gdest, gpack, cap):
-            """Per-slot merge of stayers with the arrivals addressed to the
-            slot's box (shared by both comm paths)."""
-
-            def merge(stay_r, fields_r, box_r, center_r):
-                valid = jnp.concatenate([stay_r, gdest == box_r])
-                kidx = jnp.argsort(jnp.where(valid, 0, 1))[:cap]
-                new_alive = valid[kidx]
-                out = {
-                    k: jnp.concatenate([fields_r[k], gpack[k]])[kidx] for k in _PKEYS
-                }
-                # park dead entries at the box centre, zero their payload
-                out["z"] = jnp.where(new_alive, out["z"], center_r[0])
-                out["x"] = jnp.where(new_alive, out["x"], center_r[1])
-                for k in ("ux", "uy", "uz", "w"):
-                    out[k] = jnp.where(new_alive, out[k], 0.0)
-                out["alive"] = new_alive
-                dropped_c = valid.sum() - new_alive.sum()
-                return out, dropped_c
-
-            return merge
-
         def exchange_ring(p: Particles, s: int, my_box, my_center):
             """Reference path: every pack rides the full ring (capacity-
             bounded all-to-all); every slot sees every leaver."""
@@ -932,8 +951,8 @@ class ShardedRuntime(_StragglerMixin):
                 gpack = {k: gstack[:, ki] for ki, k in enumerate(_PKEYS)}
             with jax.named_scope("merge"):
                 fields_rows = {k: getattr(p, k) for k in _PKEYS}
-                out, dropped_c = jax.vmap(make_merge(gdest, gpack, cap))(
-                    stay, fields_rows, my_box, my_center
+                out, dropped_c = merge_slots(
+                    stay, fields_rows, my_box, my_center, gdest, gpack, cap
                 )
             with jax.named_scope("diagnostics"):
                 return out, out["alive"].sum(axis=1), dropped_e + dropped_c, demand
@@ -1075,8 +1094,8 @@ class ShardedRuntime(_StragglerMixin):
                     gpack = {k: gstack[:, ki] for ki, k in enumerate(_PKEYS)}
                 with jax.named_scope("merge"):
                     fields_rows = {k: getattr(p, k) for k in _PKEYS}
-                    out, dropped_c = jax.vmap(make_merge(gdest, gpack, cap))(
-                        stay, fields_rows, my_box, my_center
+                    out, dropped_c = merge_slots(
+                        stay, fields_rows, my_box, my_center, gdest, gpack, cap
                     )
                 with jax.named_scope("diagnostics"):
                     dropped = dropped_c.at[0].add(dropped_e)
@@ -1593,7 +1612,7 @@ class ShardedRuntime(_StragglerMixin):
         (``alive``, ``(n_species, S)``) and particle lanes (``lanes``,
         ``(n_species, S)``).  One device sync per shard; never on the hot
         path.  Lanes not holding an alive particle are the dead lanes every
-        step's exchange still sorts and gathers."""
+        step's exchange still sorts."""
         self.flush()
         S = self.grid.n_boxes
         device = np.full(S, -1, np.int64)

@@ -6,6 +6,8 @@ cannot see — block shapes off the (8, 128) tiling, fast memory over its
 limit — fail here, at no chip time.  Nothing runs, so nothing here says
 anything about results or speed.
 
+The exchange's merge compiles here too, at the laser-ion cell's slot shapes.
+
 Shapes: the 256-lane counter tile at the kernel-default halo, the sharded
 runtime's padded slot tile, and the per-slot capacity of one chip's share
 of the paper's laser-ion run at its published density (900 ppc).
@@ -112,3 +114,30 @@ def test_gather_push_compiles_for_v5e(one_chip, n_boxes, cap, tile_shape):
     )
     compiled = fn.lower(counts, qm, *particles, *tiles).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_merge_compiles_for_v5e_as_one_sort_per_slot(one_chip):
+    """The exchange's merge at the laser-ion cell's slot shapes (9 slots of
+    475,136 lanes, 59,392 arrivals): one sort carries the payload, with no
+    gather, and its temporaries stay under one slot's sort operands."""
+    from repro.dist.sharded_runtime import _PKEYS, merge_slots
+
+    slots, cap, width = 9, 475_136, 59_392
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (
+        spec((slots, cap), jnp.bool_),
+        {k: spec((slots, cap)) for k in _PKEYS},
+        spec((slots,), jnp.int32),
+        spec((slots, 2)),
+        spec((width,), jnp.int32),
+        {k: spec((width,)) for k in _PKEYS},
+    )
+    compiled = jax.jit(merge_slots, static_argnums=6).lower(*args, cap).compile()
+    text = compiled.as_text()
+    assert " gather(" not in text
+    assert text.count(" sort(") == 1
+    one_slot = (1 + len(_PKEYS)) * (cap + width) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < one_slot
